@@ -35,8 +35,8 @@ def main(argv=None):
                   "mixed SSM state + shared-block KV")
     print(f"handoff payload: {state_note}")
 
-    def factory(path):
-        return RealExecutor(model, params, transfer_path=path)
+    def factory(acc):
+        return RealExecutor(model, params)
 
     streams = {}
     for setup in SETUPS:

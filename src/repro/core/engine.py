@@ -18,8 +18,8 @@ One class, three roles (DESIGN.md section 4):
 Timing comes from the roofline CostModel at the engine's DVFS setting
 ``phi`` (compute scales 1/phi, memory/interconnect do not). Energy is
 integrated per step at P(phi, utilization). In real mode the engine also
-executes a tiny model so token streams are bit-comparable across setups —
-the KV-handoff correctness test.
+executes the model (``RealExecutor``) so token streams are bit-comparable
+across setups — the KV-handoff correctness test.
 """
 from __future__ import annotations
 
@@ -695,18 +695,22 @@ class Engine:
 
 
 # ----------------------------------------------------------------------
-# Real execution (tiny models on CPU): timing stays simulated, but tokens
-# are really computed so setups can be compared bit-for-bit.
+# Real execution: timing stays simulated, but tokens are really computed
+# by the model's jitted prefill and decode steps on the executor's device
+# (a TPU chip, or the CPU in tests), so setups can be compared
+# bit-for-bit.
 # ----------------------------------------------------------------------
 class RealExecutor:
-    """Executes prefill/decode with an actual model; greedy sampling."""
+    """Executes prefill/decode with an actual model on one device; greedy
+    sampling. ``params`` are placed on ``device`` (default: the first
+    device); executors on one device can share one params pytree."""
 
-    def __init__(self, model, params, transfer_path=None):
+    def __init__(self, model, params, device=None):
         import jax
         import jax.numpy as jnp
         self.model = model
-        self.params = params
-        self.path = transfer_path
+        self.device = device or jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
         self._jnp = jnp
         self._jax = jax
 
@@ -719,35 +723,40 @@ class RealExecutor:
         return np.asarray(toks[:seq.prefill_target], dtype=np.int32)
 
     def prefill(self, seq: EngineSeq):
-        jnp = self._jnp
-        toks = jnp.asarray(self._context_tokens(seq))[None, :]
+        toks = self._jax.device_put(self._context_tokens(seq)[None, :],
+                                    self.device)
         s_max = seq.req.prompt_len + seq.req.output_len + 2
-        logits, state = self.model.prefill(
+        logits, state = self.model.jit_prefill(
             self.params, {"tokens": toks}, s_max=s_max)
-        next_token = int(jnp.argmax(logits[0]))
+        next_token = int(self._jnp.argmax(logits[0]))
         return state, logits, next_token
 
     def store(self, seq: EngineSeq):
-        payload = (seq.state, seq.last_logits)
-        if self.path is None:
-            return payload
-        return self.path.store(payload)
+        """The prefill side's handoff payload."""
+        return seq.state, seq.last_logits
 
-    def fetch(self, handle):
-        if self.path is None:
-            return handle
-        return self.path.fetch(handle)
+    def fetch(self, payload):
+        """The decode side's end of a handoff: the transfer path must have
+        landed every array on this executor's device."""
+        stray = {d for x in self._jax.tree.leaves(payload)
+                 for d in x.devices()} - {self.device}
+        if stray:
+            raise RuntimeError(f"handoff payload on {sorted(map(str, stray))}"
+                               f", not on the decode device {self.device}")
+        return payload
 
     def decode_batch(self, batch: List[EngineSeq]) -> None:
         jax, jnp = self._jax, self._jnp
-        tokens = jnp.asarray([s.next_token for s in batch], jnp.int32)
-        pos = jnp.asarray([s.ctx for s in batch], jnp.int32)
+        tokens = jax.device_put(
+            np.asarray([s.next_token for s in batch], np.int32), self.device)
+        pos = jax.device_put(
+            np.asarray([s.ctx for s in batch], np.int32), self.device)
         states = [s.state for s in batch]
         joined = jax.tree.map(
             lambda *xs: jnp.concatenate(xs, axis=1), *states)
-        logits, new_state = self.model.decode_step(
+        logits, new_state = self.model.jit_decode_step(
             self.params, tokens, joined, pos)
-        nxt = jnp.argmax(logits, axis=-1)
+        nxt = np.asarray(jnp.argmax(logits, axis=-1))
         for i, seq in enumerate(batch):
             seq.state = jax.tree.map(
                 lambda x: x[:, i:i + 1] if x.ndim > 1 else x[i:i + 1],

@@ -16,9 +16,10 @@ admission, so slower media degrade TPOT) — mirroring the LMCache connector
 structure the paper instruments. For the ici path the store pushes straight
 into decode HBM and the fetch is free.
 
-``store()``/``fetch()`` also REALLY move the state pytree at test scale
-(integration tests assert bit-exact round trips, including the disk
-serialization).
+``store()``/``fetch()`` also REALLY move the state pytree (integration
+tests assert bit-exact round trips, including the disk serialization).
+``device`` is the decode engine's device: the payload lands there (None:
+the default device).
 """
 from __future__ import annotations
 
@@ -52,13 +53,13 @@ class TransferPath:
     def fetch_cost(self, nbytes: int) -> LegCost:
         raise NotImplementedError
 
-    # real byte movement (integration tests) ------------------------------
-    def store(self, state: Any) -> Any:
+    # real byte movement ----------------------------------------------------
+    def store(self, state: Any, device=None) -> Any:
         """state pytree -> opaque handle held by the medium."""
         return state
 
-    def fetch(self, handle: Any) -> Any:
-        """handle -> state pytree on the decode side."""
+    def fetch(self, handle: Any, device=None) -> Any:
+        """handle -> state pytree on the decode side's ``device``."""
         return handle
 
 
@@ -83,11 +84,11 @@ class ICIPath(TransferPath):
     def fetch_cost(self, nbytes: int) -> LegCost:
         return LegCost(latency_s=0.0)   # already resident in decode HBM
 
-    def store(self, state: Any) -> Any:
+    def store(self, state: Any, device=None) -> Any:
         import jax
-        return jax.tree.map(lambda x: jax.device_put(x), state)
+        return jax.device_put(state, device)   # straight into decode HBM
 
-    def fetch(self, handle: Any) -> Any:
+    def fetch(self, handle: Any, device=None) -> Any:
         return handle
 
 
@@ -119,14 +120,14 @@ class HostPath(TransferPath):
     def fetch_cost(self, nbytes: int) -> LegCost:
         return self._leg(nbytes)
 
-    def store(self, state: Any) -> Any:
+    def store(self, state: Any, device=None) -> Any:
         import jax
         import numpy as np
         return jax.tree.map(lambda x: np.asarray(x), state)   # -> host DRAM
 
-    def fetch(self, handle: Any) -> Any:
+    def fetch(self, handle: Any, device=None) -> Any:
         import jax
-        return jax.tree.map(lambda x: jax.device_put(x), handle)
+        return jax.device_put(handle, device)
 
 
 class DiskPath(TransferPath):
@@ -170,7 +171,7 @@ class DiskPath(TransferPath):
             busy={"cpu": t, "dram": t, "disk": t_disk},
         )
 
-    def store(self, state: Any) -> Any:
+    def store(self, state: Any, device=None) -> Any:
         import jax
         import numpy as np
         buf = io.BytesIO()
@@ -183,12 +184,12 @@ class DiskPath(TransferPath):
             os.fsync(f.fileno())     # defeat write-back caching
         return path
 
-    def fetch(self, handle: Any) -> Any:
+    def fetch(self, handle: Any, device=None) -> Any:
         import jax
         with open(handle, "rb") as f:
             restored = pickle.load(f)
         os.unlink(handle)
-        return jax.tree.map(lambda x: jax.device_put(x), restored)
+        return jax.device_put(restored, device)
 
 
 PATHS = {"ici": ICIPath, "host": HostPath, "disk": DiskPath}

@@ -183,6 +183,9 @@ def run_grid(grid: Union[Grid, Sequence[Experiment]], *,
             misses.append((h, e))
 
     if misses and parallel > 1:
+        # forked workers run the cost model only (no executor_factory
+        # reaches here): a fork after the parent has touched a chip
+        # would hand the children a device they cannot use
         global SIM_COUNT
         from concurrent.futures import as_completed
         first_error = None

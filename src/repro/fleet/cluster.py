@@ -83,7 +83,7 @@ class FleetCluster:
                  prefill_token_budget: int = 8192,
                  pool_bytes: Optional[float] = None,
                  executor_factory: Optional[Callable[
-                     [Optional[TransferPath]], RealExecutor]] = None,
+                     [int], RealExecutor]] = None,
                  tracer: Optional[Tracer] = None):
         spec = as_fleet_spec(spec)
         if phi is not None or phi_prefill is not None \
@@ -127,6 +127,9 @@ class FleetCluster:
         def new_pool():
             return PagedKVPool.from_bytes(pool_bytes, kv_per_tok, page_size)
 
+        # executor_factory(n) builds the real executor of accelerator n
+        # (engine "acc<n>"; both slices of an intra accelerator get n), so
+        # a caller can map each accelerator to its own device
         self.engines: List[Engine] = []
         self.prefill_engines: List[Engine] = []
         self.decode_engines: List[Engine] = []
@@ -140,7 +143,7 @@ class FleetCluster:
 
         if spec.is_colocated:
             for i, phi_i in enumerate(spec.phis_prefill):
-                ex = executor_factory(None) if executor_factory else None
+                ex = executor_factory(i) if executor_factory else None
                 self.engines.append(Engine(
                     f"acc{i}", "colocated", self.cost, new_pool(),
                     self.meter, phi=phi_i,
@@ -159,8 +162,8 @@ class FleetCluster:
             for i, (phi_p, phi_d) in enumerate(zip(spec.phis_prefill,
                                                    spec.phis_decode)):
                 pool = new_pool()
-                ex_p = executor_factory(None) if executor_factory else None
-                ex_d = executor_factory(None) if executor_factory else None
+                ex_p = executor_factory(i) if executor_factory else None
+                ex_d = executor_factory(i) if executor_factory else None
                 ep = Engine(f"acc{i}p", "prefill", cost_p, pool,
                             self.meter, phi=phi_p,
                             prefill_token_budget=prefill_token_budget,
@@ -190,7 +193,7 @@ class FleetCluster:
             # at transfer time, so _transfer runs the pair path's
             # store()/fetch() around the executor's payload
             for i, phi_i in enumerate(spec.phis_prefill):
-                ex = executor_factory(None) if executor_factory else None
+                ex = executor_factory(i) if executor_factory else None
                 eng = Engine(f"acc{i}", "prefill", self.cost, new_pool(),
                              self.meter, phi=phi_i,
                              prefill_token_budget=prefill_token_budget,
@@ -198,7 +201,7 @@ class FleetCluster:
                 eng.fleet_index = i
                 self.prefill_engines.append(eng)
             for j, phi_j in enumerate(spec.phis_decode):
-                ex = executor_factory(None) if executor_factory else None
+                ex = executor_factory(x + j) if executor_factory else None
                 eng = Engine(f"acc{x + j}", "decode", self.cost, new_pool(),
                              self.meter, phi=phi_j,
                              prefill_token_budget=prefill_token_budget,
@@ -442,11 +445,13 @@ class FleetCluster:
         # the routed pair's actual LegCost, not an arbitrary 50/50 split
         for comp, joules in store.energy_j.items():
             self.meter.add(comp, joules, stage="transfer-store")
-        handle = None
+        handle = device = None
         if engine.executor is not None:
             # real byte movement over the ROUTED pair's path (the
-            # path-less executor just packages the state payload)
-            handle = path.store(engine.executor.store(seq))
+            # path-less executor just packages the state payload), landing
+            # on the decode engine's device
+            device = dec.executor.device
+            handle = path.store(engine.executor.store(seq), device)
 
         t_arrive = t_done + store.latency_s
         seq.req.transfer_done_s = t_arrive
@@ -471,7 +476,8 @@ class FleetCluster:
             # the reservation migrates from in-flight to decode_queue,
             # where the router's headroom counts it instead
             dec.inflight_kv_pages -= inflight
-            payload = path.fetch(handle) if handle is not None else None
+            payload = path.fetch(handle, device) if handle is not None \
+                else None
             dec.enqueue_decode(seq, payload, fetch)
             dec.t = max(dec.t, t_arrive)
 

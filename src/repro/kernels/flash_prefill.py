@@ -3,13 +3,17 @@
 Prefill is the compute-bound stage (paper section II-A) and sets TTFT. The
 kernel is an online-softmax flash attention with:
 
-  * BlockSpec VMEM tiling: q tile [bq, G*hd] stays resident; K/V stream
+  * BlockSpec VMEM tiling: q tile [G, bq, hd] stays resident; K/V stream
     through VMEM in [bk, hd] tiles (HBM -> VMEM pipelined by pallas grid).
   * GQA folded into the q tile: the grid iterates kv-heads and each q tile
     carries its G = H/KV query heads, so K/V tiles are fetched once per
     kv-head (not once per query head) — GQA's bandwidth saving realized.
-  * MXU-aligned tiles (q block 256, kv block 256; hd is 64/80/128 padded to
-    a lane multiple by the caller).
+    The tile is collapsed to [G*bq, hd] for one matmul; with bq a multiple
+    of 16 that collapse is tile-aligned for bf16, which Mosaic requires.
+  * Tiles of at most 256 (q) x 256 (kv). Short prompts are padded up to a
+    block (q to a multiple of 16, kv to a multiple of 128 lanes) instead of
+    shrinking the block to the prompt length, which would not fit the
+    tiling; the kpos < seq_len mask keeps the padding exact.
   * Causal block skipping: kv-blocks strictly above the diagonal contribute
     nothing and are skipped with pl.when (the dominant saving at 32k seq).
   * Optional sliding window (zamba2's shared block at long context).
@@ -59,19 +63,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(needed)
     def _body():
-        q = q_ref[...].reshape(bq * q_ref.shape[-2], q_ref.shape[-1])
-        k = k_ref[...].reshape(bk, k_ref.shape[-1])
-        v = v_ref[...].reshape(bk, v_ref.shape[-1])
-        g = q_ref.shape[-2]
+        g, hd = q_ref.shape[0], q_ref.shape[-1]
+        q = q_ref[...].reshape(g * bq, hd)
+        k = k_ref[...]
+        v = v_ref[...]
 
         s = jax.lax.dot_general(
             q.astype(jnp.float32), k.astype(jnp.float32),
             (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq*G, bk]
+            preferred_element_type=jnp.float32) * scale   # [G*bq, bk]
 
-        qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, g), 0)
-        qpos = qpos.reshape(bq * g, 1)
-        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        # row r of the collapsed tile is head r // bq at query r % bq
+        rows = jax.lax.broadcasted_iota(jnp.int32, (g * bq, bk), 0)
+        qpos = q_lo + jax.lax.rem(rows, bq)
+        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (g * bq, bk), 1)
         mask = kpos < seq_len
         if causal:
             mask = jnp.logical_and(mask, kpos <= qpos)
@@ -112,20 +117,20 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
-    bq = min(block_q, S)
-    bk = min(block_k, T)
+    bq = min(block_q, -(-S // 16) * 16)
+    bk = min(block_k, -(-T // 128) * 128)
     nq = pl.cdiv(S, bq)
     nk = pl.cdiv(T, bk)
     scale = 1.0 / np.sqrt(hd)
 
-    qg = q.reshape(B, S, KV, G, hd).transpose(0, 2, 1, 3, 4)  # [B,KV,S,G,hd]
+    qg = q.reshape(B, S, KV, G, hd).transpose(0, 2, 3, 1, 4)  # [B,KV,G,S,hd]
     kg = k.transpose(0, 2, 1, 3)                              # [B,KV,T,hd]
     vg = v.transpose(0, 2, 1, 3)
     # zero-pad to block multiples: OOB block reads would otherwise feed
     # undefined values into p @ v (0 * garbage != 0 when garbage is NaN);
     # the in-kernel kpos < seq_len mask keeps the math exact
     if nq * bq > S:
-        qg = jnp.pad(qg, [(0, 0), (0, 0), (0, nq * bq - S), (0, 0), (0, 0)])
+        qg = jnp.pad(qg, [(0, 0), (0, 0), (0, 0), (0, nq * bq - S), (0, 0)])
     if nk * bk > T:
         pad = [(0, 0), (0, 0), (0, nk * bk - T), (0, 0)]
         kg = jnp.pad(kg, pad)
@@ -140,20 +145,21 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, G, hd), lambda b, h, i, j: (b, h, i, 0, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((None, None, G, bq, hd),
+                         lambda b, h, i, j: (b, h, 0, i, 0)),
+            pl.BlockSpec((None, None, bk, hd), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((None, None, bk, hd), lambda b, h, i, j: (b, h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, G, hd),
-                               lambda b, h, i, j: (b, h, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, nq * bq, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((None, None, G, bq, hd),
+                               lambda b, h, i, j: (b, h, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, nq * bq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq * G, 1), jnp.float32),   # running max m
-            pltpu.VMEM((bq * G, 1), jnp.float32),   # running denom l
-            pltpu.VMEM((bq * G, hd), jnp.float32),  # output accumulator
+            pltpu.VMEM((G * bq, 1), jnp.float32),   # running max m
+            pltpu.VMEM((G * bq, 1), jnp.float32),   # running denom l
+            pltpu.VMEM((G * bq, hd), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
     )(qg, kg, vg)
 
-    out = out[:, :, :S].transpose(0, 2, 1, 3, 4).reshape(B, S, H, hd)
+    out = out[:, :, :, :S].transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
     return out

@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# a compile proof on the CPU backend: never take a chip that another
+# process (the parent that started this one) may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture x input shape) cell, lower + compile the
@@ -9,8 +12,9 @@ meshes — single-pod (16 data x 16 model = 256 chips) and multi-pod
 (2 pod x 16 x 16 = 512 chips) — and report memory_analysis (fits?) +
 cost_analysis (FLOPs/bytes for the roofline).
 
-The XLA_FLAGS line above MUST run before any jax import: jax locks the
-device count at first init. Do not move it; do not set it globally.
+The environment lines above MUST run before any jax import: jax locks the
+device count and platform at first init. Do not move them; do not set
+them globally.
 
 Cost-number methodology (DESIGN.md section 2): XLA counts a while-loop
 body once, so the full-size compile (rolled scan; fast, and the actual

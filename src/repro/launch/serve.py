@@ -4,10 +4,13 @@ Two modes:
   * simulation (default): one declarative ``repro.exp`` Experiment —
     TPU-target timing/energy via the roofline cost model, memoized in
     the content-addressed result cache like every figure cell.
-  * --real: reduced config executed on CPU with real KV transfers between
-    engines (correctness mode; token streams are printed/compared). Real
-    runs use an off-registry reduced config and live executors, so they
-    simulate directly and are never cached.
+  * --real: the registry config at its published widths executed on the
+    available devices (a TPU chip, or the CPU), with real KV transfers
+    between engines; accelerator n of the fleet runs on device
+    n % len(jax.devices()). Timing stays the cost model's; the token
+    streams are real and compared across setups. --smoke swaps in the
+    reduced config of the same family (CPU tests). Real runs use live
+    executors, so they simulate directly and are never cached.
 
 ``--setup`` takes a legacy setup name, the intra-GPU P/D split
 ("intra-gpu" / "intra-<k>": SM-sliced prefill+decode engines sharing
@@ -24,35 +27,44 @@ import argparse
 
 import jax
 
-from repro.configs import get_config, reduce_for_smoke
+from repro.configs import ModelConfig, get_config, reduce_for_smoke
 from repro.core import RealExecutor, SETUPS, make_cluster, random_workload
 from repro.exp import Experiment
 from repro.exp import run as run_exp
 from repro.fleet import FleetSpec
+from repro.launch.cache import use_compile_cache
 from repro.models import get_model
 
 
-def serve(arch: str, setup: str, *, batch_size: int = 16,
+def serve(arch, setup: str, *, batch_size: int = 16,
           input_len: int = 16_384, output_len: int = 256,
           phi: float = 1.0, governor: str = None, real: bool = False,
-          seed: int = 0, verbose: bool = True):
+          smoke: bool = False, seed: int = 0, verbose: bool = True):
+    """Serve one closed batch. ``arch`` is a registry name, or (real mode
+    only) a ``ModelConfig``; ``smoke`` reduces it for the CPU."""
     if real:
-        cfg = reduce_for_smoke(get_config(arch))
-        input_len = min(input_len, 64)
-        output_len = min(output_len, 8)
+        cfg = arch if isinstance(arch, ModelConfig) else get_config(arch)
+        if smoke:
+            cfg = reduce_for_smoke(cfg)
+        arch = cfg.name
         model = get_model(cfg)
         params = model.init(jax.random.PRNGKey(seed))
+        devices = jax.devices()
+        placed = {}                    # one params copy per device
 
-        def executor_factory(path):
-            return RealExecutor(model, params, transfer_path=path)
+        def executor_factory(acc):
+            dev = devices[acc % len(devices)]
+            if dev not in placed:
+                placed[dev] = jax.device_put(params, dev)
+            return RealExecutor(model, placed[dev], device=dev)
 
         reqs = random_workload(batch_size, input_len=input_len,
                                output_len=output_len,
                                vocab_size=cfg.vocab_size, seed=seed)
         kw = {"governor": governor} if governor else {}
-        res = make_cluster(setup, cfg, phi=phi,
-                           executor_factory=executor_factory,
-                           **kw).run(reqs)
+        cluster = make_cluster(setup, cfg, phi=phi,
+                               executor_factory=executor_factory, **kw)
+        res = cluster.run(reqs)
     else:
         exp = Experiment.closed(setup, batch_size, arch=arch,
                                 input_len=input_len,
@@ -66,6 +78,10 @@ def serve(arch: str, setup: str, *, batch_size: int = 16,
         gov = f" governor={governor}" if governor else ""
         print(f"[serve] {setup} arch={arch} bs={batch_size} "
               f"phi={phi}{gov}")
+        if real:
+            print("  devices: " + "  ".join(
+                f"{e.name}={e.executor.device}" for e in cluster.engines)
+                + "  (times and energy below: cost model)")
         print(f"  median TTFT {m.median_ttft_s:.3f}s  "
               f"median TPOT {m.median_tpot_s * 1e3:.2f}ms")
         print(f"  prefill tput {m.prefill_throughput_tok_s:.0f} tok/s  "
@@ -94,6 +110,8 @@ def main(argv=None):
                     help="online DVFS governor (repro.govern): "
                          "static / queue-depth / slo-slack")
     ap.add_argument("--real", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="with --real: the reduced config (CPU tests)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     if args.setup not in SETUPS:
@@ -101,10 +119,11 @@ def main(argv=None):
             FleetSpec.parse(args.setup)
         except ValueError as e:
             ap.error(str(e))          # usage error, not a traceback
+    use_compile_cache()
     serve(args.arch, args.setup, batch_size=args.batch_size,
           input_len=args.input_len, output_len=args.output_len,
           phi=args.phi, governor=args.governor, real=args.real,
-          seed=args.seed)
+          smoke=args.smoke, seed=args.seed)
 
 
 if __name__ == "__main__":

@@ -133,6 +133,18 @@ class Model:
             return VL.decode_step(params, tokens, state, pos, cfg)
         raise ValueError(self.family)
 
+    # The served path's compiled entry points, one jit cache per model so
+    # every executor of the model shares its compiled programs. The
+    # kernel backend (repro.kernels.ops) is read when a shape is first
+    # traced.
+    @functools.cached_property
+    def jit_prefill(self):
+        return jax.jit(self.prefill, static_argnames=("s_max",))
+
+    @functools.cached_property
+    def jit_decode_step(self):
+        return jax.jit(self.decode_step)
+
     # ------------------------------------------------------------------
     def init_decode_state(self, batch_size: int, s_max: int,
                           dtype=jnp.bfloat16, s_src: int = 0) -> Any:

@@ -16,15 +16,14 @@ _WORKER = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import PartitionSpec as P
-
-    from repro.compat import shard_map
+    from jax.sharding import AxisType, PartitionSpec as P
+    from jax import shard_map
 
     from repro.dist.collectives import (bucketed_psum, compressed_psum,
                                         halo_exchange, ring_allgather,
                                         ring_pass)
 
-    mesh = jax.make_mesh((8,), ("dp",))
+    mesh = jax.make_mesh((8,), ("dp",), axis_types=(AxisType.Auto,))
     results = {}
 
     # --- compressed all-reduce: mean within int8 tolerance + EF ----------

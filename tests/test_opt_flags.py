@@ -3,8 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
-from repro.compat import abstract_mesh
 from repro.configs import REGISTRY, reduce_for_smoke
 from repro.dist import opt_flags
 from repro.dist.sharding import state_spec
@@ -65,7 +65,7 @@ def test_opt_flags_preserve_grads():
 
 
 def test_seq_shard_kv_changes_cache_spec():
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     kv_shape = (28, 128, 32768, 8, 128)
     base = state_spec(kv_shape, mesh)
     assert base[4] == "model" and base[2] is None

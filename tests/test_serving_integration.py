@@ -17,8 +17,8 @@ def _run_all_setups(arch, *, n_req=3, in_len=48, out_len=6,
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
 
-    def factory(path):
-        return RealExecutor(model, params, transfer_path=path)
+    def factory(acc):
+        return RealExecutor(model, params)
 
     kv_tok = max(cfg.kv_bytes_per_token(), 1)
     pool_bytes = kv_tok * (pool_tokens or (in_len + out_len) * n_req * 2)

@@ -3,15 +3,14 @@ production meshes (divisibility respected; fallback chain ends replicated)."""
 import jax
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.dist.sharding import (batch_spec, param_spec, state_spec)
 from repro.models import get_model
 
-SINGLE = abstract_mesh((16, 16), ("data", "model"))
-MULTI = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _path_str(path):

@@ -61,12 +61,14 @@ def test_real_roundtrip_bit_exact(name, tmp_path):
     kw = {"scratch_dir": str(tmp_path)} if name == "disk" else {}
     path = make_path(name, **kw)
     payload = _payload()
-    handle = path.store(payload)
-    back = path.fetch(handle)
+    device = jax.devices()[-1]        # the decode engine's device
+    handle = path.store(payload, device)
+    back = path.fetch(handle, device)
     for key in payload:
         np.testing.assert_array_equal(np.asarray(back[key]),
                                       np.asarray(payload[key]))
         assert back[key].dtype == payload[key].dtype
+        assert back[key].devices() == {device}
 
 
 def test_disk_file_removed_after_fetch(tmp_path):
