@@ -34,7 +34,11 @@ from .costs import CostModel, StepCost
 from .energy import EnergyMeter
 from .kvcache import OutOfPages, PagedKVPool
 from .request import Request
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import (DECODE_DISPATCH_SPAN, DECODE_JOIN_SPAN,
+                             DECODE_SPAN, DECODE_SPLIT_SPAN,
+                             DECODE_SYNC_SPAN, NULL_TRACER,
+                             PREFILL_DISPATCH_SPAN, PREFILL_SPAN,
+                             PREFILL_SYNC_SPAN)
 
 
 @dataclass(eq=False)
@@ -703,16 +707,22 @@ class Engine:
 class RealExecutor:
     """Executes prefill/decode with an actual model on one device; greedy
     sampling. ``params`` are placed on ``device`` (default: the first
-    device); executors on one device can share one params pytree."""
+    device); executors on one device can share one params pytree.
+
+    Each call's phases are wall-clock spans on the profiler's clock,
+    named in ``repro.obs.trace``; they record only while a profiler
+    runs."""
 
     def __init__(self, model, params, device=None):
         import jax
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
         self.model = model
         self.device = device or jax.devices()[0]
         self.params = jax.device_put(params, self.device)
         self._jnp = jnp
         self._jax = jax
+        self._span = TraceAnnotation
 
     def _context_tokens(self, seq: EngineSeq) -> np.ndarray:
         """prompt + already-emitted tokens (recompute path needs both)."""
@@ -723,12 +733,16 @@ class RealExecutor:
         return np.asarray(toks[:seq.prefill_target], dtype=np.int32)
 
     def prefill(self, seq: EngineSeq):
-        toks = self._jax.device_put(self._context_tokens(seq)[None, :],
-                                    self.device)
-        s_max = seq.req.prompt_len + seq.req.output_len + 2
-        logits, state = self.model.jit_prefill(
-            self.params, {"tokens": toks}, s_max=s_max)
-        next_token = int(self._jnp.argmax(logits[0]))
+        span = self._span
+        with span(PREFILL_SPAN):
+            with span(PREFILL_DISPATCH_SPAN):
+                toks = self._jax.device_put(
+                    self._context_tokens(seq)[None, :], self.device)
+                s_max = seq.req.prompt_len + seq.req.output_len + 2
+                logits, state = self.model.jit_prefill(
+                    self.params, {"tokens": toks}, s_max=s_max)
+            with span(PREFILL_SYNC_SPAN):
+                next_token = int(self._jnp.argmax(logits[0]))
         return state, logits, next_token
 
     def store(self, seq: EngineSeq):
@@ -746,19 +760,29 @@ class RealExecutor:
         return payload
 
     def decode_batch(self, batch: List[EngineSeq]) -> None:
-        jax, jnp = self._jax, self._jnp
-        tokens = jax.device_put(
-            np.asarray([s.next_token for s in batch], np.int32), self.device)
-        pos = jax.device_put(
-            np.asarray([s.ctx for s in batch], np.int32), self.device)
-        states = [s.state for s in batch]
-        joined = jax.tree.map(
-            lambda *xs: jnp.concatenate(xs, axis=1), *states)
-        logits, new_state = self.model.jit_decode_step(
-            self.params, tokens, joined, pos)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        for i, seq in enumerate(batch):
-            seq.state = jax.tree.map(
-                lambda x: x[:, i:i + 1] if x.ndim > 1 else x[i:i + 1],
-                new_state)
-            seq.next_token = int(nxt[i])
+        jax, jnp, span = self._jax, self._jnp, self._span
+        with span(DECODE_SPAN):
+            # the per-request caches are joined along the batch axis and
+            # split back out on every step; one span per phase, none per
+            # request, so the spans cost the same at any batch size
+            with span(DECODE_JOIN_SPAN):
+                tokens = jax.device_put(
+                    np.asarray([s.next_token for s in batch], np.int32),
+                    self.device)
+                pos = jax.device_put(
+                    np.asarray([s.ctx for s in batch], np.int32),
+                    self.device)
+                states = [s.state for s in batch]
+                joined = jax.tree.map(
+                    lambda *xs: jnp.concatenate(xs, axis=1), *states)
+            with span(DECODE_DISPATCH_SPAN):
+                logits, new_state = self.model.jit_decode_step(
+                    self.params, tokens, joined, pos)
+            with span(DECODE_SYNC_SPAN):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with span(DECODE_SPLIT_SPAN):
+                for i, seq in enumerate(batch):
+                    seq.state = jax.tree.map(
+                        lambda x: x[:, i:i + 1] if x.ndim > 1
+                        else x[i:i + 1], new_state)
+                    seq.next_token = int(nxt[i])

@@ -24,6 +24,13 @@ Determinism contract (DESIGN.md section 16, locked by
     (a coalesced window batches its finish emissions, so only the
     cross-engine interleaving of the event *list* may differ).
 
+The executed path's wall-clock spans are named here too (``*_SPAN``),
+beside the simulation-clock tracks but on another clock: ``RealExecutor``
+writes them with ``jax.profiler.TraceAnnotation``, so they land on the
+profiler's clock, in the same trace as the device's ops, and only while
+a profiler runs. They never reach a ``Tracer`` and leave the contract
+above untouched.
+
 This module is dependency-free at import time (stdlib only):
 ``repro.core.engine`` imports it, so it must not import ``repro``
 back. The converters at the bottom single-source the three event
@@ -38,8 +45,11 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["SPAN", "INSTANT", "LIFECYCLE_TRACK", "GOVERNOR_TRACK",
-           "CONTROLLER_TRACK", "TIER_TRACK", "TraceEvent", "Tracer",
-           "NULL_TRACER", "event_from_governor_decision",
+           "CONTROLLER_TRACK", "TIER_TRACK", "DECODE_SPAN",
+           "DECODE_JOIN_SPAN", "DECODE_DISPATCH_SPAN", "DECODE_SYNC_SPAN",
+           "DECODE_SPLIT_SPAN", "PREFILL_SPAN", "PREFILL_DISPATCH_SPAN",
+           "PREFILL_SYNC_SPAN", "TraceEvent", "Tracer", "NULL_TRACER",
+           "event_from_governor_decision",
            "governor_decision_from_event", "event_from_controller_action",
            "controller_action_from_event"]
 
@@ -53,6 +63,22 @@ CONTROLLER_TRACK = "controller"
 TIER_TRACK = "tier"
 _RESERVED_TRACKS = (LIFECYCLE_TRACK, GOVERNOR_TRACK, CONTROLLER_TRACK,
                     TIER_TRACK)
+
+# Wall-clock spans of the executed path (profiler clock, not the
+# simulation clock). Each child lies inside its parent, in this order:
+# decode = join (tokens, positions and the per-request caches joined on
+# the device), dispatch (the jitted step called), sync (the host waits
+# for the step's tokens), split (each request's cache sliced back out);
+# prefill = dispatch (prompt put on the device, jitted prefill called),
+# sync.
+DECODE_SPAN = "repro.decode"
+DECODE_JOIN_SPAN = "repro.decode.join"
+DECODE_DISPATCH_SPAN = "repro.decode.dispatch"
+DECODE_SYNC_SPAN = "repro.decode.sync"
+DECODE_SPLIT_SPAN = "repro.decode.split"
+PREFILL_SPAN = "repro.prefill"
+PREFILL_DISPATCH_SPAN = "repro.prefill.dispatch"
+PREFILL_SYNC_SPAN = "repro.prefill.sync"
 
 # Lifecycle instants: the arrival/first_token/finish triple is emitted
 # exactly once per request (the property suite pins this); the rest may
