@@ -16,12 +16,13 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import json
 import shutil
 import sys
 
-from bench import check, proxy, spec, traffic, weights, window
+from bench import check, proxy, spec, trace, traffic, weights, window
 
 TRACE_DIR = spec.REPO_DIR / ".bench_trace"
 
@@ -212,13 +213,77 @@ def measure(server: Server, seed: int, seconds: float, counter):
 
 
 class LayerContext:
-    """What a per-layer metric reader sees."""
+    """What a per-layer metric reader sees. ``prefills`` and
+    ``decode_steps`` are the whole window's calls, for readers that read
+    no device event. A reader that divides work by device time takes
+    ``region``, ``covered_prefills`` and ``covered_decode_steps``: the
+    covered bursts' stretches of the window and their calls
+    (``trace.covered``), since a trace may lose device events."""
 
     def __init__(self, tr, cfg, peaks, chips, stamps):
         self.trace, self.cfg, self.peaks, self.chips = tr, cfg, peaks, chips
+        self.stamps = stamps            # one Stamps per burst, in order
         self.prefills = [S for st in stamps for S in st.prefills]
         self.decode_steps = [c for st in stamps for c in st.decode_steps]
         self.notes = []
+
+    @functools.cached_property
+    def bursts(self) -> list:
+        return trace.bursts(self.trace)
+
+    @functools.cached_property
+    def covered(self) -> list:
+        return trace.covered(self.trace)
+
+    def _need_cover(self) -> None:
+        if not self.covered:
+            raise RuntimeError(
+                f"the trace covers none of the window's {len(self.bursts)} "
+                f"bursts: in each, some executor call's program event is "
+                f"missing from the {len(self.trace.devices)} device(s) "
+                "traced, so no device time matches the calls it would be "
+                "divided by")
+
+    @property
+    def region(self) -> list:
+        self._need_cover()
+        return trace.segments(self.trace, self.covered)
+
+    @functools.cached_property
+    def _covered_stamps(self) -> list:
+        """The covered bursts' Stamps, each with as many calls as the
+        burst's call spans."""
+        self._need_cover()
+        if len(self.stamps) != len(self.bursts):
+            raise RuntimeError(f"{len(self.stamps)} bursts ran, the trace "
+                               f"holds {len(self.bursts)} bench.burst spans")
+        out = []
+        for i in self.covered:
+            st, held = self.stamps[i], trace.calls(self.trace,
+                                                   self.bursts[i])
+            if (len(st.prefills), len(st.decode_steps)) != (
+                    len(held["bench.prefill"]),
+                    len(held["bench.decode_batch"])):
+                raise RuntimeError(f"burst {i}: the calls made and the "
+                                   "trace's call spans differ in number")
+            out.append(st)
+        return out
+
+    @property
+    def covered_prefills(self) -> list:
+        return [S for st in self._covered_stamps for S in st.prefills]
+
+    @property
+    def covered_decode_steps(self) -> list:
+        return [c for st in self._covered_stamps for c in st.decode_steps]
+
+    def coverage(self) -> str:
+        cov = self._covered_stamps if self.covered else []
+        return (f"trace covers {len(self.covered)} of {len(self.bursts)} "
+                f"bursts, {sum(len(st.prefills) for st in cov)} of "
+                f"{len(self.prefills)} prefills, "
+                f"{sum(len(st.decode_steps) for st in cov)} of "
+                f"{len(self.decode_steps)} decode steps")
 
 
 def run(args, t_start: float, *, root=spec.BENCH_DIR, bench=None,
@@ -274,12 +339,13 @@ def run(args, t_start: float, *, root=spec.BENCH_DIR, bench=None,
 
     result_metrics, device_extra, breakdown = {}, {}, None
     if traced:
-        from bench import trace
         tr = trace.load(str(TRACE_DIR))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         ctx = LayerContext(tr, cfg, peaks, len(devices), stamps)
-        lo, hi = trace.window(tr)
-        device_extra = {"busy_s": trace.busy_s(tr), "window_s": hi - lo}
+        log(ctx.coverage())
+        region = ctx.region
+        device_extra = {"busy_s": trace.busy_s(tr, region),
+                        "window_s": trace.length(region)}
         for m in spec.metrics_of(bench, cell, "per_layer"):
             value = spec.load_reader(m["name"], root)(ctx)
             if value is not None:
@@ -287,8 +353,8 @@ def run(args, t_start: float, *, root=spec.BENCH_DIR, bench=None,
                                              "unit": m["unit"]}
         for note in ctx.notes:
             log(note)
-        breakdown = {"device_ops": trace.top_ops(tr),
-                     "idle_gaps": trace.idle_gaps(tr)}
+        breakdown = {"device_ops": trace.top_ops(tr, region=region),
+                     "idle_gaps": trace.idle_gaps(tr, region=region)}
     else:
         summary["setup_s"] = setup_s
         for m in spec.metrics_of(bench, cell, "end_to_end"):

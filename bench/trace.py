@@ -8,11 +8,20 @@ the profiler's clock, which host and device events share.
 
 Everything else here is interval arithmetic over a ``Trace`` and is
 checked on a small recorded trace in ``tests/bench/``.
+
+A long or busy traced window can lose device events: the profiler keeps
+a bounded number of trace buffers a chip, while every host span stays.
+So a reading that divides the window's calls by their device time takes
+both over the bursts whose every executor call holds its program's
+event (``covered``), and over those bursts' stretches of the window
+(``segments``). Where every burst is covered, the stretches are the
+window, and every reading is what the whole window gives.
 """
 from __future__ import annotations
 
 import glob
 import re
+from bisect import bisect_right
 from collections import defaultdict
 from dataclasses import dataclass, field
 
@@ -28,6 +37,9 @@ GAP_LABELS = (("bench.decode_batch", "decode_batch"),
               ("bench.prefill", "prefill"), ("bench.gc", "gc"),
               ("bench.burst", "loop"), ("bench.window", "window"))
 EXECUTOR_SPANS = ("bench.decode_batch", "bench.prefill")
+# executor call span -> the program each call runs once, synced inside it
+PROGRAMS = (("bench.prefill", "jit_prefill"),
+            ("bench.decode_batch", "jit_decode_step"))
 # ops whose time is their body's, which the trace lists as well
 CONTAINER_KINDS = ("while", "conditional", "call")
 _LAYOUT = re.compile(r"\{[^{}]*\}")
@@ -153,7 +165,8 @@ def length(a: list) -> float:
 
 
 # ----------------------------------------------------------------------
-# what the per-layer metrics read
+# what the per-layer metrics read; ``region`` is a sorted, disjoint
+# [(lo, hi)] list inside the window, by default the whole window
 # ----------------------------------------------------------------------
 def spans(tr: Trace, name: str) -> list:
     return [(lo, hi) for n, lo, hi in tr.host if n == name]
@@ -167,32 +180,50 @@ def window(tr: Trace) -> tuple:
     return w[0]
 
 
-def busy(tr: Trace, dev: str, lo: float, hi: float) -> list:
+def _region(tr: Trace, region) -> list:
+    return [window(tr)] if region is None else region
+
+
+def _inside(s: float, e: float, region: list) -> bool:
+    for lo, hi in region:
+        if s >= lo and e <= hi:
+            return True
+    return False
+
+
+def busy(tr: Trace, dev: str, region: list) -> list:
     evs = [(s, e) for _, s, e in tr.devices[dev].get("ops", [])]
-    return intersect(union(evs), [(lo, hi)])
+    return intersect(union(evs), region)
 
 
-def busy_s(tr: Trace) -> float:
-    """Seconds of the window in which an op ran, averaged over devices."""
-    lo, hi = window(tr)
+def busy_s(tr: Trace, region=None) -> float:
+    """Seconds of ``region`` in which an op ran, averaged over devices."""
+    region = _region(tr, region)
     if not tr.devices:
         return 0.0
-    return float(np.mean([length(busy(tr, d, lo, hi))
+    return float(np.mean([length(busy(tr, d, region))
                           for d in tr.devices]))
 
 
-def events(tr: Trace, line: str, prefix: str) -> list:
+def events(tr: Trace, line: str, prefix: str, region=None) -> list:
     """(device, name, start, end) of ``line`` events whose name starts
-    with ``prefix``, inside the window."""
-    lo, hi = window(tr)
+    with ``prefix``, inside ``region``."""
+    region = _region(tr, region)
     return [(d, n, s, e) for d, lines in tr.devices.items()
             for n, s, e in lines.get(line, [])
-            if n.startswith(prefix) and s >= lo and e <= hi]
+            if n.startswith(prefix) and _inside(s, e, region)]
 
 
-def device_time(tr: Trace, line: str, prefix: str) -> float:
-    """Summed device seconds of matching events in the window."""
-    return float(sum(e - s for _, _, s, e in events(tr, line, prefix)))
+def device_time(tr: Trace, line: str, prefix: str, region=None) -> float:
+    """Summed device seconds of matching events in ``region``."""
+    return float(sum(e - s for _, _, s, e in
+                     events(tr, line, prefix, region)))
+
+
+def span_time(tr: Trace, name: str, region=None) -> float:
+    """Host seconds of the named spans, clipped to ``region``."""
+    return sum(min(e, hi) - max(s, lo) for s, e in spans(tr, name)
+               for lo, hi in _region(tr, region) if e > lo and s < hi)
 
 
 def host_share(tr: Trace, names) -> float:
@@ -203,16 +234,83 @@ def host_share(tr: Trace, names) -> float:
     return length(covered) / (hi - lo)
 
 
-def idle_gaps(tr: Trace, top: int = 10) -> list:
-    """[(label, seconds)] of device idle time in the window, put down to
+def bursts(tr: Trace) -> list:
+    """The window's ``bench.burst`` spans, in order."""
+    lo, hi = window(tr)
+    return sorted(iv for iv in spans(tr, "bench.burst")
+                  if iv[0] >= lo and iv[1] <= hi)
+
+
+def calls(tr: Trace, burst: tuple) -> dict:
+    """Executor call span name -> that span's intervals inside
+    ``burst``."""
+    return {name: [iv for iv in spans(tr, name)
+                   if iv[0] >= burst[0] and iv[1] <= burst[1]]
+            for name, _ in PROGRAMS}
+
+
+def _held(evs: list, ivs: list) -> list:
+    """For each of the sorted, disjoint spans ``ivs``, how many of the
+    events ``evs`` overlap it more than any other span. Events are
+    matched by overlap, not by containment: on the profiler's clock a
+    program's event may begin a fraction of a millisecond before the
+    host span of the call that launched it."""
+    starts = [lo for lo, _ in ivs]
+    held = [0] * len(ivs)
+    for s, e in evs:
+        best, most = None, 0.0
+        for k in range(bisect_right(starts, e) - 1, -1, -1):
+            lo, hi = ivs[k]
+            if hi <= s:
+                break
+            if min(e, hi) - max(s, lo) > most:
+                best, most = k, min(e, hi) - max(s, lo)
+        if best is not None:
+            held[best] += 1
+    return held
+
+
+def covered(tr: Trace) -> list:
+    """Indices into ``bursts(tr)`` of the bursts whose every executor
+    call span holds exactly one event of its program (``PROGRAMS``) on
+    the devices' module lines. Both programs are synced inside their
+    call, so a call whose event is missing lost it to the profiler."""
+    lo, hi = window(tr)
+    whole = {}
+    for span, program in PROGRAMS:
+        ivs = sorted(iv for iv in spans(tr, span)
+                     if iv[0] >= lo and iv[1] <= hi)
+        evs = [(s, e) for lines in tr.devices.values()
+               for n, s, e in lines.get("modules", [])
+               if n.startswith(program)]
+        whole.update((iv, n == 1) for iv, n in zip(ivs, _held(evs, ivs)))
+    return [i for i, b in enumerate(bursts(tr))
+            if all(whole[iv] for ivs in calls(tr, b).values()
+                   for iv in ivs)]
+
+
+def segments(tr: Trace, idx) -> list:
+    """The stretches of the window that belong to bursts ``idx``: each
+    runs from the end of the burst before it (the window's start, for
+    the first) to its own end (the window's end, for the last), so the
+    collector's pause between two bursts goes with the later one.
+    Adjacent stretches are merged, so all bursts' make ``[window(tr)]``."""
+    lo, hi = window(tr)
+    bs = bursts(tr)
+    ends = [e for _, e in bs[:-1]] + [hi]
+    return union([((ends[i - 1] if i else lo), ends[i]) for i in idx])
+
+
+def idle_gaps(tr: Trace, top: int = 10, region=None) -> list:
+    """[(label, seconds)] of device idle time in ``region``, put down to
     the innermost benchmark span the host was in, averaged over
     devices, longest first."""
-    lo, hi = window(tr)
+    region = _region(tr, region)
     totals = defaultdict(float)
     label_ivs = [(label, union(spans(tr, name)))
                  for name, label in GAP_LABELS]
     for dev in tr.devices:
-        idle = subtract([(lo, hi)], busy(tr, dev, lo, hi))
+        idle = subtract(region, busy(tr, dev, region))
         for label, ivs in label_ivs:
             hit = intersect(idle, ivs)
             totals[label] += length(hit) / len(tr.devices)
@@ -223,14 +321,15 @@ def idle_gaps(tr: Trace, top: int = 10) -> list:
     return [[k, v] for k, v in ranked[:top]]
 
 
-def top_ops(tr: Trace, top: int = 10) -> list:
-    """[(op label, device seconds summed over devices)], longest first;
-    loops and calls are left out, their bodies' ops are counted."""
-    lo, hi = window(tr)
+def top_ops(tr: Trace, top: int = 10, region=None) -> list:
+    """[(op label, device seconds summed over devices)] in ``region``,
+    longest first; loops and calls are left out, their bodies' ops are
+    counted."""
+    region = _region(tr, region)
     totals = defaultdict(float)
     for lines in tr.devices.values():
         for n, s, e in lines.get("ops", []):
-            if s >= lo and e <= hi and n.rsplit(" ", 1)[-1] \
+            if _inside(s, e, region) and n.rsplit(" ", 1)[-1] \
                     not in CONTAINER_KINDS:
                 totals[n] += e - s
     ranked = sorted(totals.items(), key=lambda kv: -kv[1])
