@@ -24,6 +24,7 @@ across setups — the KV-handoff correctness test.
 from __future__ import annotations
 
 import bisect
+import functools
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -48,7 +49,8 @@ class EngineSeq:
     prefill_done: int = 0
     ctx: int = 0                   # materialized KV tokens in the pool
     # real-mode payload
-    state: Any = None              # decode-state pytree (batch axis 1, B=1)
+    state: Any = None              # decode-state pytree (batch axis 1, B=1);
+                                   # None once the executor's batch holds it
     last_logits: Any = None
     next_token: Optional[int] = None
     # tiered-KV bookkeeping (repro.kvstore): the TierLookup from submit
@@ -597,6 +599,8 @@ class Engine:
             seq.req.finish_s = t_end
             self.pool.free_seq(seq.seq_id)
             self.running.remove(seq)
+            if not self.running and self.executor is not None:
+                self.executor.release_batch()
             if self.tracer.enabled:
                 self.tracer.lifecycle("finish", seq.req.req_id,
                                       t_end, engine=self.name)
@@ -704,10 +708,54 @@ class Engine:
 # (a TPU chip, or the CPU in tests), so setups can be compared
 # bit-for-bit.
 # ----------------------------------------------------------------------
+def batch_axis(leaf) -> int:
+    """The batch axis of a decode-state leaf: 1 (``[L, B, ...]``), or 0
+    for a rank-1 leaf. Every model family's state follows this rule."""
+    return 1 if leaf.ndim > 1 else 0
+
+
+def _regroup(resident, keep, newcomers, order):
+    """A batch cache re-formed in one program: the ``keep`` rows of
+    ``resident`` (None: no row survives), then each newcomer's
+    one-row state, joined along the batch axis and, where ``order`` is
+    not None, permuted into the batch's order."""
+    import jax
+    import jax.numpy as jnp
+
+    def leaf(*xs):
+        ax = batch_axis(xs[0])
+        rows = list(xs)
+        if keep is not None:
+            rows[0] = jnp.take(rows[0], keep, axis=ax)
+        out = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=ax)
+        return out if order is None else jnp.take(out, order, axis=ax)
+
+    parts = ((resident,) if keep is not None else ()) + tuple(newcomers)
+    return jax.tree.map(leaf, *parts)
+
+
+@functools.cache
+def _jit_regroup():
+    """One jit cache of ``_regroup`` for every executor."""
+    import jax
+    return jax.jit(_regroup)
+
+
 class RealExecutor:
     """Executes prefill/decode with an actual model on one device; greedy
     sampling. ``params`` are placed on ``device`` (default: the first
     device); executors on one device can share one params pytree.
+
+    The decode batch's cache stays resident between steps: the cache
+    the last ``jit_decode_step`` returned, with the ``seq_id`` of each
+    row, is the only copy. A step whose batch has the same ids in the
+    same order and no newcomer passes it straight back in; any other
+    batch first re-forms it in one jitted regroup. A newcomer is a
+    sequence whose ``state`` holds a state (fresh from ``prefill`` or
+    ``fetch``, also after a preemption); the regroup absorbs it and
+    sets ``state`` to None. ``decode_calls`` and ``decode_regroups``
+    count the steps and the regroups among them. One executor serves
+    one decoding engine.
 
     Each call's phases are wall-clock spans on the profiler's clock,
     named in ``repro.obs.trace``; they record only while a profiler
@@ -723,6 +771,10 @@ class RealExecutor:
         self._jnp = jnp
         self._jax = jax
         self._span = TraceAnnotation
+        self._resident = None       # the batch cache, rows in _resident_ids
+        self._resident_ids: Tuple[int, ...] = ()
+        self.decode_calls = 0
+        self.decode_regroups = 0
 
     def _context_tokens(self, seq: EngineSeq) -> np.ndarray:
         """prompt + already-emitted tokens (recompute path needs both)."""
@@ -759,12 +811,63 @@ class RealExecutor:
                                f", not on the decode device {self.device}")
         return payload
 
+    def release_batch(self) -> None:
+        """Drop the resident batch cache. The engine calls it when its
+        decode batch empties, so a closed burst holds no cache into the
+        next; an empty ``decode_batch`` would do the same, but wrappers
+        of the executor count each of those calls as a step."""
+        self._resident, self._resident_ids = None, ()
+
+    def _join(self, batch: List[EngineSeq], ids: Tuple[int, ...]):
+        """The batch cache for ``batch`` (whose ``seq_id``s are ``ids``):
+        the resident one as it is when the batch is the resident rows in
+        order, else one regroup."""
+        if ids == self._resident_ids and \
+                all(s.state is None for s in batch):
+            return self._resident
+        self.decode_regroups += 1
+        row = {sid: i for i, sid in enumerate(self._resident_ids)}
+        keep, newcomers, order = [], [], []
+        for seq in batch:
+            if seq.state is not None:
+                order.append(("new", len(newcomers)))
+                newcomers.append(seq.state)
+            elif seq.seq_id in row:
+                order.append(("keep", len(keep)))
+                keep.append(row[seq.seq_id])
+            else:
+                raise RuntimeError(
+                    f"sequence {seq.seq_id} has neither a decode state nor "
+                    f"a row of the resident batch")
+        # the regroup puts the kept rows first: map each batch position
+        # to its row there
+        perm = [i if src == "keep" else len(keep) + i for src, i in order]
+        # drop the executor's own reference first: with no row kept, the
+        # old cache is freed before the new one is made
+        resident = self._resident if keep else None
+        self._resident, self._resident_ids = None, ()
+        joined = _jit_regroup()(
+            resident, np.asarray(keep, np.int32) if keep else None,
+            newcomers,
+            None if perm == list(range(len(perm)))
+            else np.asarray(perm, np.int32))
+        for seq in batch:
+            seq.state = None
+        return joined
+
     def decode_batch(self, batch: List[EngineSeq]) -> None:
+        """One decode step of ``batch``; an empty batch drops the
+        resident cache."""
+        if not batch:
+            self.release_batch()
+            return
         jax, jnp, span = self._jax, self._jnp, self._span
+        self.decode_calls += 1
         with span(DECODE_SPAN):
-            # the per-request caches are joined along the batch axis and
-            # split back out on every step; one span per phase, none per
-            # request, so the spans cost the same at any batch size
+            # one span per phase, none per request, so the spans cost the
+            # same at any batch size; join is the tokens and positions put
+            # on the device (and a regroup when the batch changed), split
+            # the tokens handed back
             with span(DECODE_JOIN_SPAN):
                 tokens = jax.device_put(
                     np.asarray([s.next_token for s in batch], np.int32),
@@ -772,17 +875,14 @@ class RealExecutor:
                 pos = jax.device_put(
                     np.asarray([s.ctx for s in batch], np.int32),
                     self.device)
-                states = [s.state for s in batch]
-                joined = jax.tree.map(
-                    lambda *xs: jnp.concatenate(xs, axis=1), *states)
+                ids = tuple(s.seq_id for s in batch)
+                joined = self._join(batch, ids)
             with span(DECODE_DISPATCH_SPAN):
-                logits, new_state = self.model.jit_decode_step(
+                logits, self._resident = self.model.jit_decode_step(
                     self.params, tokens, joined, pos)
+                self._resident_ids = ids
             with span(DECODE_SYNC_SPAN):
                 nxt = np.asarray(jnp.argmax(logits, axis=-1))
             with span(DECODE_SPLIT_SPAN):
-                for i, seq in enumerate(batch):
-                    seq.state = jax.tree.map(
-                        lambda x: x[:, i:i + 1] if x.ndim > 1
-                        else x[i:i + 1], new_state)
-                    seq.next_token = int(nxt[i])
+                for seq, tok in zip(batch, nxt.tolist()):
+                    seq.next_token = tok

@@ -66,9 +66,10 @@ _RESERVED_TRACKS = (LIFECYCLE_TRACK, GOVERNOR_TRACK, CONTROLLER_TRACK,
 
 # Wall-clock spans of the executed path (profiler clock, not the
 # simulation clock). Each child lies inside its parent, in this order:
-# decode = join (tokens, positions and the per-request caches joined on
-# the device), dispatch (the jitted step called), sync (the host waits
-# for the step's tokens), split (each request's cache sliced back out);
+# decode = join (tokens and positions put on the device, and the
+# resident batch cache regrouped when the batch changed), dispatch (the
+# jitted step called), sync (the host waits for the step's tokens),
+# split (the tokens handed back to each request);
 # prefill = dispatch (prompt put on the device, jitted prefill called),
 # sync.
 DECODE_SPAN = "repro.decode"
