@@ -44,7 +44,7 @@ def calibrate(cell_name: str, seeds: list, control: bool, *,
     limits = spec.load_limits(cell["name"], root)
     n_sample = limits["sample_requests"]
     devices, _ = harness.open_devices(cell, require_chip)
-    server = harness.Server(cfg, mix, devices, fault=fault)
+    server = harness.Server(cfg, mix, devices, fault=fault, root=root)
     n_bursts = max(1, math.ceil(n_sample / mix["burst_requests"]))
     out = []
     for seed in seeds:
@@ -55,7 +55,8 @@ def calibrate(cell_name: str, seeds: list, control: bool, *,
             reqs.extend(server.burst(seed, index)[1])
         server.unload()
         sample = check.sample(reqs, seed, n_sample)
-        r = check.readings(cfg, seed, devices[0], sample, control=control)
+        r = check.readings(server.arch, cfg, seed, devices[0], sample,
+                           control=control)
         if control:
             r["control_correct"] = check.judge(
                 {**r, "gap": r["control_gap"]}, limits, len(reqs), 0)[0]
@@ -73,13 +74,14 @@ def served_control(cell_name: str, seeds: list, *, root=spec.BENCH_DIR,
     bench = bench or spec.load_benchmark()
     cell = spec.find_cell(bench, cell_name)
     cfg = spec.load_config(cell["config"], root)
+    fault = control.served(spec.load_arch(cfg, root), cfg)
     out = []
     for seed in seeds:
         args = harness.parse_args(["--workload", cell_name, "--seed",
                                    str(seed), "--seconds", "0.01",
                                    "--trace", "0"])
         res = harness.run(args, time.perf_counter(), root=root, bench=bench,
-                          fault=control.served(cfg),
+                          fault=fault,
                           require_chip=require_chip)
         r = {"seed": seed, "correct": res["correct"],
              "compared": res["compared"]}

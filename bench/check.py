@@ -2,10 +2,11 @@
 
 After the window has closed and the program's state is freed, a sample
 of the window's finished requests, drawn from the seed, is run through
-the float32 reference (``bench.reference``) over its prompt and the
-tokens the timed path served. The number compared is the widest gap by
-which a served token's reference logit lies below the reference's best
-at that position; greedy decoding that matches the reference reads 0.
+the float32 reference of the configuration's architecture module
+(``archs/<arch>.py``) over its prompt and the tokens the timed path
+served. The number compared is the widest gap by which a served token's
+reference logit lies below the reference's best at that position; greedy
+decoding that matches the reference reads 0.
 
 ``limits/<cell>.json`` gives, per cell, ``sample_requests`` and, for each
 number compared, its ``limit`` with the readings it was set from.
@@ -41,22 +42,23 @@ def _row(req):
     return toks, served, len(prompt) - 1
 
 
-def readings(cfg: dict, seed: int, device, reqs: list,
+def readings(arch, cfg: dict, seed: int, device, reqs: list,
              control: bool = False) -> dict:
-    """Widest gap of the served tokens against the reference (``gap``),
-    and with ``control`` the widest gap of the tokens the fp8 control
-    puts first (``control_gap``), over the requests ``reqs``."""
+    """Widest gap of the served tokens against the reference of the
+    architecture module ``arch`` (``gap``), and with ``control`` the
+    widest gap of the tokens the fp8 control puts first
+    (``control_gap``), over the requests ``reqs``."""
     import jax
-    params = weights.make_params(cfg, seed, device)
+    params = weights.make_params(arch, cfg, seed, device)
     gaps, ctl, n = [], [], 0
     for req in reqs:
         toks, served, first = _row(req)
         toks = jax.device_put(toks, device)
-        ref = np.asarray(reference.logits(params, cfg, toks, first))[0]
+        ref = np.asarray(arch.logits(params, cfg, toks, first))[0]
         gaps.append(reference.widest_gap(ref, served))
         n += len(served)
         if control:
-            low = reference.logits(params, cfg, toks, first, fp8=True)
+            low = arch.logits(params, cfg, toks, first, fp8=True)
             ctl.append(reference.widest_gap(
                 ref, np.asarray(low[0].argmax(-1))))
     del params

@@ -1,9 +1,10 @@
 """The fp8 control put in the program's place.
 
-``served(cfg)`` wraps each executor so that every token it serves, the
-first from ``prefill`` and each later one from ``decode_batch``, is the
-argmax of the reference computed in float8 (``bench.reference`` with
-``fp8=True``) over the request's prompt and the tokens served before it.
+``served(arch, cfg)`` wraps each executor so that every token it serves,
+the first from ``prefill`` and each later one from ``decode_batch``, is
+the argmax of the reference of the architecture module ``arch`` computed
+in float8 (its ``logits`` with ``fp8=True``) over the request's prompt and
+the tokens served before it.
 The program still runs underneath and its cache is fed the control's
 tokens, so the rest of a run (window, sample, reference, ``check.judge``)
 is the timed path's own. A sound comparison reads such a run as not
@@ -18,12 +19,11 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from bench import reference
-
 
 class Fp8Served:
-    def __init__(self, inner, cfg: dict):
+    def __init__(self, inner, arch, cfg: dict):
         self._inner = inner
+        self._arch = arch
         self._cfg = cfg
 
     def __getattr__(self, name):
@@ -40,8 +40,8 @@ class Fp8Served:
         toks[0, :len(ctx)] = ctx
         first = req.prompt_len - 1
         dev = self._inner.device
-        logits = reference.logits(self._inner.params, self._cfg,
-                                  jax.device_put(toks, dev), first, fp8=True)
+        logits = self._arch.logits(self._inner.params, self._cfg,
+                                   jax.device_put(toks, dev), first, fp8=True)
         return int(np.asarray(logits[0, len(ctx) - 1 - first]).argmax())
 
     def prefill(self, seq):
@@ -54,7 +54,7 @@ class Fp8Served:
             s.next_token = self._next(s)
 
 
-def served(cfg: dict):
-    """The ``fault`` for ``harness.run``: the fp8 control in place of
-    each executor's tokens."""
-    return lambda inner: Fp8Served(inner, cfg)
+def served(arch, cfg: dict):
+    """The ``fault`` for ``harness.run``: the fp8 control of the
+    architecture module ``arch`` in place of each executor's tokens."""
+    return lambda inner: Fp8Served(inner, arch, cfg)

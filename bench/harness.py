@@ -15,7 +15,6 @@ with the collector run at every burst boundary and nowhere else.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import functools
 import gc
 import json
@@ -68,20 +67,6 @@ def open_devices(cell: dict, require_chip: bool = True):
     return devices, cache_dir
 
 
-def program_config(cfg: dict):
-    """The program's ``ModelConfig`` with every size from the file."""
-    from repro.configs import get_config
-    return dataclasses.replace(
-        get_config(cfg["registry"]),
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"], qk_norm=cfg["qk_norm"],
-        param_dtype=cfg["torch_dtype"], compute_dtype=cfg["torch_dtype"])
-
-
 class CompileCounter:
     """New executables (XLA compiles and persistent-cache loads) and the
     seconds spent compiling, from JAX's monitoring events."""
@@ -108,13 +93,17 @@ class CompileCounter:
 
 
 class Server:
-    """The model, its executors on ``devices`` and the cell's traffic."""
+    """The model, its executors on ``devices`` and the cell's traffic.
+    ``arch`` is the architecture module that ``cfg`` names, under
+    ``root``."""
 
-    def __init__(self, cfg: dict, mix: dict, devices: list, fault=None):
+    def __init__(self, cfg: dict, mix: dict, devices: list, fault=None,
+                 root=spec.BENCH_DIR):
         from repro.fleet.spec import as_fleet_spec
         from repro.models import get_model
         self.cfg, self.mix, self.devices = cfg, mix, devices
-        self.model_cfg = program_config(cfg)
+        self.arch = spec.load_arch(cfg, root)
+        self.model_cfg = self.arch.program_config(cfg)
         self.model = get_model(self.model_cfg)
         if not as_fleet_spec(mix["setup"]).is_colocated:
             raise ValueError(f"setup {mix['setup']!r}: only colocated "
@@ -130,7 +119,7 @@ class Server:
         """Weights from the seed on the first device, one copy per
         device; executors are made as the clusters ask for them."""
         import jax
-        p = weights.make_params(self.cfg, seed, self.devices[0])
+        p = weights.make_params(self.arch, self.cfg, seed, self.devices[0])
         want = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
         got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                            p)
@@ -218,10 +207,12 @@ class LayerContext:
     no device event. A reader that divides work by device time takes
     ``region``, ``covered_prefills`` and ``covered_decode_steps``: the
     covered bursts' stretches of the window and their calls
-    (``trace.covered``), since a trace may lose device events."""
+    (``trace.covered``), since a trace may lose device events. ``root``
+    is where a reader finds the counts of ``cfg``'s architecture."""
 
-    def __init__(self, tr, cfg, peaks, chips, stamps):
+    def __init__(self, tr, cfg, peaks, chips, stamps, root=spec.BENCH_DIR):
         self.trace, self.cfg, self.peaks, self.chips = tr, cfg, peaks, chips
+        self.root = root
         self.stamps = stamps            # one Stamps per burst, in order
         self.prefills = [S for st in stamps for S in st.prefills]
         self.decode_steps = [c for st in stamps for c in st.decode_steps]
@@ -303,7 +294,7 @@ def run(args, t_start: float, *, root=spec.BENCH_DIR, bench=None,
     log(f"{args.workload}: {len(devices)} x {kind}; compile cache "
         f"{cache_dir}")
 
-    server = Server(cfg, mix, devices, fault=fault)
+    server = Server(cfg, mix, devices, fault=fault, root=root)
     t_import = proxy.now() - t_start
     server.load(args.seed)
     t_init = proxy.now() - t_start
@@ -341,7 +332,7 @@ def run(args, t_start: float, *, root=spec.BENCH_DIR, bench=None,
     if traced:
         tr = trace.load(str(TRACE_DIR))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        ctx = LayerContext(tr, cfg, peaks, len(devices), stamps)
+        ctx = LayerContext(tr, cfg, peaks, len(devices), stamps, root)
         log(ctx.coverage())
         region = ctx.region
         device_extra = {"busy_s": trace.busy_s(tr, region),
@@ -365,7 +356,8 @@ def run(args, t_start: float, *, root=spec.BENCH_DIR, bench=None,
     server.unload()
     sample = check.sample(reqs, args.seed, limits["sample_requests"])
     t_ref = proxy.now()
-    reading = check.readings(cfg, args.seed, devices[0], sample)
+    reading = check.readings(server.arch, cfg, args.seed, devices[0],
+                             sample)
     log(f"reference over {reading['requests']} requests, "
         f"{reading['tokens']} served tokens: {proxy.now() - t_ref!r} s")
     correct, compared = check.judge(reading, limits, summary["requests"],

@@ -11,6 +11,6 @@ def read(ctx):
     dev = trace.device_time(ctx.trace, "modules", PROGRAM, ctx.region)
     if not ctx.decode_steps or dev <= 0:
         return None
-    flops = sum(load_counts("decode_step").flops(ctx.cfg, c)
-                for c in ctx.covered_decode_steps)
+    counts = load_counts("decode_step", ctx.cfg, ctx.root)
+    flops = sum(counts.flops(ctx.cfg, c) for c in ctx.covered_decode_steps)
     return 100.0 * flops / (dev * ctx.peaks["bf16_flops"])

@@ -12,7 +12,7 @@ def read(ctx):
     dev = trace.device_time(ctx.trace, "modules", PROGRAM, ctx.region)
     if not ctx.decode_steps or dev <= 0:
         return None
-    counts = load_counts("decode_step")
+    counts = load_counts("decode_step", ctx.cfg, ctx.root)
     pk = ctx.peaks
     least = memory = 0.0
     for c in ctx.covered_decode_steps:

@@ -14,7 +14,7 @@ def read(ctx):
     dev = trace.device_time(ctx.trace, "ops", KERNEL, ctx.region)
     if not ctx.prefills or dev <= 0:
         return None
-    counts = load_counts("flash_prefill")
+    counts = load_counts("flash_prefill", ctx.cfg, ctx.root)
     pk = ctx.peaks
     least = compute = 0.0
     for S in ctx.covered_prefills:

@@ -6,7 +6,8 @@ from bench.spec import load_counts
 
 def read(ctx):
     lo, hi = trace.window(ctx.trace)
-    prefill, decode = load_counts("prefill_step"), load_counts("decode_step")
+    prefill, decode = (load_counts(name, ctx.cfg, ctx.root)
+                       for name in ("prefill_step", "decode_step"))
     flops = (sum(prefill.flops(ctx.cfg, S) for S in ctx.prefills)
              + sum(decode.flops(ctx.cfg, c) for c in ctx.decode_steps))
     if not flops:

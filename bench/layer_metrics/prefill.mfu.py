@@ -11,6 +11,6 @@ def read(ctx):
     dev = trace.device_time(ctx.trace, "modules", PROGRAM, ctx.region)
     if not ctx.prefills or dev <= 0:
         return None
-    flops = sum(load_counts("prefill_step").flops(ctx.cfg, S)
-                for S in ctx.covered_prefills)
+    counts = load_counts("prefill_step", ctx.cfg, ctx.root)
+    flops = sum(counts.flops(ctx.cfg, S) for S in ctx.covered_prefills)
     return 100.0 * flops / (dev * ctx.peaks["bf16_flops"])

@@ -1,19 +1,26 @@
-"""Find a cell's parts by name: its configuration, traffic mix, limits,
-per-layer metric readers, FLOP and byte counts, and the device's peaks.
+"""Find a cell's parts by name: its configuration, the architecture
+module it names, traffic mix, limits, per-layer metric readers, FLOP and
+byte counts, and the device's peaks.
 
-Each part is a file of its own under ``bench/``, so a new cell, mix or
-metric is added as new files and entries, never by editing one:
+Each part is a file of its own under ``bench/``, so a new cell, mix,
+metric or architecture is added as new files and entries, never by
+editing one:
 
-  configs/<config>.json        sizes as run, with source and cuts
+  configs/<config>.json        sizes as run, with source, cuts and "arch"
+  archs/<arch>.py              ``program_config``, ``shapes``, ``std``,
+                               ``logits`` (the plain reference), and the
+                               counts as attributes: ``prefill_step``,
+                               ``decode_step``, ``flash_prefill``, each
+                               with ``flops`` and, where a roofline needs
+                               them, ``bytes``
   traffic/<mix>.json           parameters of one closed-burst mix
   limits/<cell>.json           the limit of each number compared
   layer_metrics/<metric>.py    ``read(ctx)`` -> value, or None
-  counts/<name>.py             FLOPs and bytes of one kernel or step
   peaks.json                   peaks by ``device_kind``, with source
 """
 from __future__ import annotations
 
-import importlib
+import functools
 import importlib.util
 import json
 import re
@@ -42,7 +49,18 @@ def find_cell(bench: dict, name: str) -> dict:
 
 
 def load_config(name: str, root: Path = BENCH_DIR) -> dict:
-    return _json(root / "configs" / f"{name}.json")
+    """``configs/<name>.json``; its ``"arch"`` must name a module of
+    ``archs/``, and there is no default."""
+    path = root / "configs" / f"{name}.json"
+    cfg = _json(path)
+    arch = cfg.get("arch")
+    if not (isinstance(arch, str) and (root / "archs" / f"{arch}.py")
+            .is_file()):
+        known = ", ".join(sorted(p.stem for p in (root / "archs")
+                                 .glob("*.py")))
+        raise KeyError(f"{path}: \"arch\" is {arch!r}, which names no "
+                       f"module of {root / 'archs'} ({known})")
+    return cfg
 
 
 def load_traffic(name: str, root: Path = BENCH_DIR) -> dict:
@@ -77,10 +95,22 @@ def load_reader(metric: str, root: Path = BENCH_DIR):
     return _load_module(root / "layer_metrics" / f"{metric}.py").read
 
 
-def load_counts(name: str):
-    """The module ``counts/<name>.py`` (``flops``, and ``bytes`` where
-    a roofline needs them)."""
-    return importlib.import_module(f"bench.counts.{name}")
+@functools.lru_cache(maxsize=None)
+def _arch(path: Path):
+    return _load_module(path)
+
+
+def load_arch(cfg: dict, root: Path = BENCH_DIR):
+    """The module ``archs/<arch>.py`` that ``cfg["arch"]`` names, loaded
+    once per file, so its compiled reference is shared by its callers."""
+    return _arch(root / "archs" / f"{cfg['arch']}.py")
+
+
+def load_counts(name: str, cfg: dict, root: Path = BENCH_DIR):
+    """The counts ``name`` (``flops``, and ``bytes`` where a roofline
+    needs them) of the architecture ``cfg`` names: an attribute of its
+    module."""
+    return getattr(load_arch(cfg, root), name)
 
 
 def metrics_of(bench: dict, cell: dict, kind: str) -> list:

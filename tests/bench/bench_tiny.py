@@ -23,22 +23,27 @@ def tiny_config() -> dict:
 
 
 def make_root(tmp: Path, setup: str = "co-1gpu", limit: float = 0.003,
-              requests: int = 4, prompt: int = 32, out: int = 8) -> dict:
-    """A benchmark directory with one tiny cell ``tiny.mix``, made only
-    of new files; returns the BENCHMARK.json-like dict for it."""
+              requests: int = 4, prompt: int = 32, out: int = 8,
+              config: dict = None) -> dict:
+    """A benchmark directory with one tiny cell ``<config>.mix`` (by
+    default ``tiny.mix``), made only of new files; returns the
+    BENCHMARK.json-like dict for it."""
     from bench import spec
-    for d in ("configs", "traffic", "limits", "layer_metrics"):
+    for d in ("configs", "traffic", "limits", "layer_metrics", "archs"):
         (tmp / d).mkdir(parents=True, exist_ok=True)
-    for f in (spec.BENCH_DIR / "layer_metrics").glob("*.py"):
-        shutil.copy(f, tmp / "layer_metrics")
-    (tmp / "configs/tiny.json").write_text(json.dumps(tiny_config()))
+    for d in ("layer_metrics", "archs"):
+        for f in (spec.BENCH_DIR / d).glob("*.py"):
+            shutil.copy(f, tmp / d)
+    config = config or tiny_config()
+    cell = f"{config['name']}.mix"
+    (tmp / f"configs/{config['name']}.json").write_text(json.dumps(config))
     (tmp / "traffic/mix.json").write_text(json.dumps(
         {"setup": setup, "burst_requests": requests, "prompt_len": prompt,
          "output_len": out}))
-    (tmp / "limits/tiny.mix.json").write_text(json.dumps(
+    (tmp / f"limits/{cell}.json").write_text(json.dumps(
         {"sample_requests": requests, "min_tokens": requests * out,
          "max_logit_gap": {"limit": limit}}))
     committed = spec.load_benchmark()
-    return {"workloads": [{"name": "tiny.mix", "config": "tiny",
+    return {"workloads": [{"name": cell, "config": config["name"],
                            "traffic": "mix", "chips": 1}],
             "end_to_end": committed["end_to_end"], "per_layer": []}

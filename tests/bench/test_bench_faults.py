@@ -6,7 +6,7 @@ import time
 
 import pytest
 
-from bench import control, harness
+from bench import control, harness, spec
 
 import bench_tiny
 
@@ -78,7 +78,7 @@ def test_fault_is_not_correct(tmp_path, fault):
 
 def test_served_fp8_control_is_not_correct(tmp_path):
     cfg = bench_tiny.tiny_config()
-    out = _run(tmp_path, fault=control.served(cfg))
+    out = _run(tmp_path, fault=control.served(spec.load_arch(cfg), cfg))
     assert out["correct"] is False
     assert out["failed"] == 0
     gap = out["compared"]["max_logit_gap"]

@@ -1,9 +1,12 @@
-"""The harness finds a configuration, a traffic mix, a limit and a
-per-layer metric by name, each one file, and a cell added as new files
-runs with no file of ``bench/`` edited. Also checks BENCHMARK.json
-against the files it names."""
+"""The harness finds a configuration, the architecture module it names,
+a traffic mix, a limit and a per-layer metric by name, each one file, and
+a cell added as new files runs with no file of ``bench/`` edited. Also
+checks BENCHMARK.json against the files it names."""
 import json
+import os
 import re
+import subprocess
+import sys
 import time
 
 import pytest
@@ -78,6 +81,56 @@ def test_unknown_names_are_errors(tmp_path):
         spec.find_cell(spec.load_benchmark(), "no.such.cell")
 
 
+@pytest.mark.parametrize("arch", [None, "no_such_arch"])
+def test_config_without_a_known_arch_names_its_file(tmp_path, arch):
+    bench_tiny.make_root(tmp_path)
+    path = tmp_path / "configs" / "tiny.json"
+    cfg = json.loads(path.read_text())
+    cfg.pop("arch")
+    if arch:
+        cfg["arch"] = arch
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(KeyError, match=re.escape(str(path))):
+        spec.load_config("tiny", tmp_path)
+
+
+# the dense module loaded, its weights made and its reference run with
+# every import of ``repro`` refused; only ``program_config`` reaches it
+NO_REPRO = """
+import importlib.abc, sys
+class Refuse(importlib.abc.MetaPathFinder):
+    def find_spec(self, name, path=None, target=None):
+        if name.split(".")[0] == "repro":
+            raise ImportError("refused: " + name)
+sys.meta_path.insert(0, Refuse())
+sys.path[:0] = [{tests!r}, {repo!r}]
+import jax, numpy as np
+import bench_tiny
+from bench import spec, weights
+cfg = bench_tiny.tiny_config()
+arch = spec.load_arch(cfg)
+p = weights.make_params(arch, cfg, 5, jax.devices()[0])
+toks = np.arange(12, dtype=np.int32)[None]
+assert arch.logits(p, cfg, toks, 8).shape == (1, 4, cfg["vocab_size"])
+assert spec.load_counts("decode_step", cfg).flops(cfg, [8]) > 0
+assert not [m for m in sys.modules if m.split(".")[0] == "repro"]
+try:
+    arch.program_config(cfg)
+except ImportError as e:
+    print("program_config:", e)
+"""
+
+
+def test_dense_arch_imports_nothing_of_the_program():
+    code = NO_REPRO.format(tests=str(spec.REPO_DIR / "tests" / "bench"),
+                           repo=str(spec.REPO_DIR))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert "program_config: refused: repro" in r.stdout
+
+
 def test_benchmark_json_names_its_files():
     b = spec.load_benchmark()
     assert b["command"][:2] == ["python3", "bench/run.py"]
@@ -89,6 +142,7 @@ def test_benchmark_json_names_its_files():
         assert f["name"] == c["name"] and f["source"] == c["source"]
         assert c["file"] == f"bench/configs/{c['name']}.json"
         assert sorted(c["reduced"]) == sorted(f["reduced"])
+        assert callable(spec.load_arch(f).program_config)
     names = {c["name"] for c in b["configs"]}
     cells = [w["name"] for w in b["workloads"]]
     assert len(cells) == len(set(cells))
